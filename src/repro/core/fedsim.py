@@ -76,7 +76,7 @@ import functools
 import os
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +98,10 @@ from repro.sharding.rules import (client_axis_spec, client_stack_shardings,
 PyTree = Any
 
 METHODS = ("local", "fedavg", "fedprox", "perfedavg", "fedamp", "pfedwn")
+
+# the named scopes of a round block's phases (a method runs a subset); a
+# trace reads each phase's device time from them (``device_scopes``)
+PHASES = ("local_sgd", "em", "mix", "post_sgd", "eval")
 
 # test rows whose activations one eval chunk keeps live (at the paper's
 # CIFAR-10 width on a v5e, one client's 1,198 test rows take 0.6 GiB of
@@ -180,6 +184,33 @@ def block_schedule(rounds: int, eval_every: int) -> List[int]:
     return blocks
 
 
+class RoundBlock:
+    """One method's round block: ``fn(state, data, length)`` jitted once per
+    block length, with donated ``state``. Each jit is named for the method
+    and the length (``pfedwn_block_r1``), so a device trace's module names
+    say which block ran and no two executables share op names."""
+
+    def __init__(self, fn: Callable, name: str):
+        self._fn, self._name = fn, name
+        self._jits: Dict[int, Any] = {}
+
+    def jit(self, length: int):
+        """The donated jit scanning ``length`` rounds."""
+        length = int(length)
+        if length not in self._jits:
+            fn = self._fn
+
+            def at_length(state, data):
+                return fn(state, data, length)
+
+            at_length.__name__ = f"{self._name}_r{length}"
+            self._jits[length] = jax.jit(at_length, donate_argnums=(0,))
+        return self._jits[length]
+
+    def lower(self, state, data, length: int):
+        return self.jit(length).lower(state, data)
+
+
 class FederatedSimulation:
     """target client = index 0 by convention; clients 1..N-1 are neighbors."""
 
@@ -215,10 +246,10 @@ class FederatedSimulation:
         self._m = len(self._neighbor_idx)
         self._data = None                      # staged on the first run
         self._stage_data()
-        self._blocks: Dict[str, Any] = {}      # method -> donated block jit
+        self._blocks: Dict[str, RoundBlock] = {}  # method -> round block
         self._block_execs: Dict[Tuple, Any] = {}  # (engine, method, len) AOT
         self._legacy: Dict[str, Any] = {}      # per-phase jits, built lazily
-        self._sharded_blocks: Dict[str, Any] = {}
+        self._sharded_blocks: Dict[str, RoundBlock] = {}
         self._client_mesh = None               # built on first sharded run
         self.last_run_stats: Dict[str, Any] = {}
 
@@ -421,65 +452,85 @@ class FederatedSimulation:
             train_x, train_y = data.train_x, data.train_y
             params, pi, key = state
             key, k_sample, k_erase = jax.random.split(key, 3)
-            idx = sample_idx(k_sample)
             link_rate = jnp.float32(1.0)
+            with jax.named_scope("local_sgd"):
+                idx = sample_idx(k_sample)
 
             if method == "local":
-                params, train_loss = local_all(params, train_x, train_y, idx)
+                with jax.named_scope("local_sgd"):
+                    params, train_loss = local_all(params, train_x, train_y,
+                                                   idx)
                 eff_nbr = jnp.float32(0.0)
 
             elif method == "fedavg":
-                params, train_loss = local_all(params, train_x, train_y, idx)
-                g = baselines.fedavg_aggregate(params, sizes, pm)
-                params = baselines.broadcast_global(g, params, pm)
+                with jax.named_scope("local_sgd"):
+                    params, train_loss = local_all(params, train_x, train_y,
+                                                   idx)
+                with jax.named_scope("mix"):
+                    g = baselines.fedavg_aggregate(params, sizes, pm)
+                    params = baselines.broadcast_global(g, params, pm)
                 eff_nbr = nbr_count
 
             elif method == "fedprox":
-                g = baselines.fedavg_aggregate(params, sizes, pm)
-                params, train_loss = prox_all(params, g, pmf, train_x,
-                                              train_y, idx)
-                g = baselines.fedavg_aggregate(params, sizes, pm)
-                params = baselines.broadcast_global(g, params, pm)
+                with jax.named_scope("mix"):
+                    g = baselines.fedavg_aggregate(params, sizes, pm)
+                with jax.named_scope("local_sgd"):
+                    params, train_loss = prox_all(params, g, pmf, train_x,
+                                                  train_y, idx)
+                with jax.named_scope("mix"):
+                    g = baselines.fedavg_aggregate(params, sizes, pm)
+                    params = baselines.broadcast_global(g, params, pm)
                 eff_nbr = nbr_count
 
             elif method == "perfedavg":
-                params, train_loss = maml_all(params, train_x, train_y, idx)
-                g = baselines.fedavg_aggregate(params, sizes, pm)
-                params = baselines.broadcast_global(g, params, pm)
+                with jax.named_scope("local_sgd"):
+                    params, train_loss = maml_all(params, train_x, train_y,
+                                                  idx)
+                with jax.named_scope("mix"):
+                    g = baselines.fedavg_aggregate(params, sizes, pm)
+                    params = baselines.broadcast_global(g, params, pm)
                 eff_nbr = nbr_count
 
             elif method == "fedamp":
-                xi = baselines.fedamp_weights(params, sim.fedamp_sigma, pm,
-                                              sim.fedamp_self_weight)
-                cloud = baselines.fedamp_cloud_models(params, xi)
-                params, train_loss = amp_all(params, cloud, train_x,
-                                             train_y, idx)
+                with jax.named_scope("mix"):
+                    xi = baselines.fedamp_weights(params, sim.fedamp_sigma,
+                                                  pm, sim.fedamp_self_weight)
+                    cloud = baselines.fedamp_cloud_models(params, xi)
+                with jax.named_scope("local_sgd"):
+                    params, train_loss = amp_all(params, cloud, train_x,
+                                                 train_y, idx)
                 eff_nbr = nbr_count
 
             elif method == "pfedwn":
                 # 1. everyone trains locally (neighbors included)
-                params, train_loss = local_all(params, train_x, train_y, idx)
+                with jax.named_scope("local_sgd"):
+                    params, train_loss = local_all(params, train_x, train_y,
+                                                   idx)
                 # 2-4. target: EM weights + erasure-gated aggregation
-                target = jax.tree.map(lambda p: p[0], params)
-                neighbors = jax.tree.map(lambda p: p[nbr], params)
-                if sim.em_uniform:
-                    pi_new = jnp.full((M,), 1.0 / max(M, 1))
-                else:
-                    _, pi_new, _ = em_refine_loop(
-                        fns, neighbors, pi, data.em_x, data.em_y,
-                        iters=sim.em_iters, lr=lr, min_weight=em_min_w,
-                        component_steps=sim.em_component_steps)
-                if sim.erasures:
-                    link_ok = link_success_mask(k_erase, p_err_nbr)
-                else:
-                    link_ok = jnp.ones((M,), bool)
-                mixed = aggregation.mix_params_with_erasures(
-                    target, neighbors, pi_new, sim.alpha, link_ok)
+                with jax.named_scope("em"):
+                    target = jax.tree.map(lambda p: p[0], params)
+                    neighbors = jax.tree.map(lambda p: p[nbr], params)
+                    if sim.em_uniform:
+                        pi_new = jnp.full((M,), 1.0 / max(M, 1))
+                    else:
+                        _, pi_new, _ = em_refine_loop(
+                            fns, neighbors, pi, data.em_x, data.em_y,
+                            iters=sim.em_iters, lr=lr, min_weight=em_min_w,
+                            component_steps=sim.em_component_steps)
+                with jax.named_scope("mix"):
+                    if sim.erasures:
+                        link_ok = link_success_mask(k_erase, p_err_nbr)
+                    else:
+                        link_ok = jnp.ones((M,), bool)
+                    mixed = aggregation.mix_params_with_erasures(
+                        target, neighbors, pi_new, sim.alpha, link_ok)
                 # 5. target trains locally from the aggregate
-                mixed, loss0 = sgd_one(mixed, train_x[0], train_y[0], idx[0])
-                params = jax.tree.map(
-                    lambda s, t: s.at[0].set(t.astype(s.dtype)),
-                    params, mixed)
+                with jax.named_scope("post_sgd"):
+                    mixed, loss0 = sgd_one(mixed, train_x[0], train_y[0],
+                                           idx[0])
+                    params = jax.tree.map(
+                        lambda s, t: s.at[0].set(t.astype(s.dtype)),
+                        params, mixed)
                 pi = pi_new
                 # the target's tap entry tracks the post-aggregation pass
                 train_loss = train_loss.at[0].set(loss0)
@@ -506,6 +557,7 @@ class FederatedSimulation:
         pmf = self.participants.astype(jnp.float32)
         fns = self.fns
 
+        @jax.named_scope("eval")
         def eval_fn(params, data: BlockData):
             test_x, test_y, test_mask = (data.test_x, data.test_y,
                                          data.test_mask)
@@ -523,12 +575,12 @@ class FederatedSimulation:
 
     # --------------------------------------------------------- fused engine
 
-    def block_fn(self, method: str):
-        """The donated, jitted round-block runner for ``method``:
-        ``block(state, data, length)`` scans ``length`` rounds and
-        evaluates, all in one compiled executable (``length`` is static;
-        ``state`` buffers are donated so params update in place where the
-        backend allows; ``data`` is :meth:`staged_data`, not donated)."""
+    def block_fn(self, method: str) -> RoundBlock:
+        """The donated round-block runner for ``method``: ``block.lower(state,
+        data, length)`` lowers the jit that scans ``length`` rounds and
+        evaluates, all in one compiled executable (``state`` buffers are
+        donated so params update in place where the backend allows;
+        ``data`` is :meth:`staged_data`, not donated)."""
         method = method.lower()
         if method not in self._blocks:
             body = self._make_round_body(method)
@@ -543,29 +595,40 @@ class FederatedSimulation:
                 t_acc, mean_acc = eval_fn(params, data)
                 return state, (t_acc, mean_acc, pi, taps)
 
-            self._blocks[method] = jax.jit(block, static_argnums=(2,),
-                                           donate_argnums=(0,))
+            self._blocks[method] = RoundBlock(block, f"{method}_block")
         return self._blocks[method]
 
     def _compiled_block(self, method: str, length: int, state,
                         data) -> Any:
         """AOT-compiled executable for one (engine, method, block length)
-        shape, cached; compilation is spanned and its FLOP/byte cost
-        estimate is recorded as a compile event."""
+        shape, cached; compilation is spanned and the span and a compile
+        event carry its FLOP/byte cost estimate."""
         key = (self.engine, method, int(length))
         exe = self._block_execs.get(key)
         if exe is None:
             block = (self.sharded_block_fn(method)
                      if self.engine == "sharded" else self.block_fn(method))
-            t0 = time.perf_counter()
             with self.recorder.span("compile", cat="compile", method=method,
-                                    rounds=length):
+                                    rounds=length) as sp:
                 exe = block.lower(state, data, length).compile()
-            self.recorder.record_compile(
+            sp.set(**self.recorder.record_compile(
                 f"{method}/block{length}", compiled=exe,
-                seconds=time.perf_counter() - t0)
+                seconds=sp.duration_s))
             self._block_execs[key] = exe
         return exe
+
+    def device_scopes(self) -> Dict[str, Dict[str, Tuple]]:
+        """``{module name: {op: (phase or None, layer or None)}}`` for every
+        round block compiled so far: the scope of each op of its optimized
+        HLO (:func:`repro.obs.scopes.op_scopes`), for attributing a device
+        trace's op time to :data:`PHASES` and the model's
+        :data:`repro.models.cnn.SCOPES`. Built only when called."""
+        out = {}
+        for exe in self._block_execs.values():
+            text = exe.as_text()
+            out[obs.hlo_module_name(text)] = obs.op_scopes(
+                text, PHASES, cnn.SCOPES)
+        return out
 
     def initial_state(self) -> Tuple[PyTree, jax.Array, jax.Array]:
         """(params, π, key) at round 0. Params are a fresh copy so donated
@@ -690,75 +753,93 @@ class FederatedSimulation:
                 params, pi, key = state
                 ofs = jax.lax.axis_index("clients") * S
                 key, k_sample, k_erase = jax.random.split(key, 3)
-                idx = sample_idx(k_sample)       # replicated full-N draw:
-                idx_l = slab(idx, ofs)           # same stream as fused/legacy
                 link_rate = jnp.float32(1.0)
+                with jax.named_scope("local_sgd"):
+                    idx = sample_idx(k_sample)   # replicated full-N draw:
+                    idx_l = slab(idx, ofs)       # same stream as fused/legacy
 
                 if method == "local":
-                    params, train_loss = local_all(params, tx, ty, idx_l)
+                    with jax.named_scope("local_sgd"):
+                        params, train_loss = local_all(params, tx, ty, idx_l)
                     eff_nbr = jnp.float32(0.0)
 
                 elif method == "fedavg":
-                    params, train_loss = local_all(params, tx, ty, idx_l)
-                    params = bcast(gmean(params, ofs), params, ofs)
+                    with jax.named_scope("local_sgd"):
+                        params, train_loss = local_all(params, tx, ty, idx_l)
+                    with jax.named_scope("mix"):
+                        params = bcast(gmean(params, ofs), params, ofs)
                     eff_nbr = nbr_count
 
                 elif method == "fedprox":
-                    g = gmean(params, ofs)
-                    params, train_loss = prox_all(params, g, slab(pmf, ofs),
-                                                  tx, ty, idx_l)
-                    params = bcast(gmean(params, ofs), params, ofs)
+                    with jax.named_scope("mix"):
+                        g = gmean(params, ofs)
+                    with jax.named_scope("local_sgd"):
+                        params, train_loss = prox_all(
+                            params, g, slab(pmf, ofs), tx, ty, idx_l)
+                    with jax.named_scope("mix"):
+                        params = bcast(gmean(params, ofs), params, ofs)
                     eff_nbr = nbr_count
 
                 elif method == "perfedavg":
-                    params, train_loss = maml_all(params, tx, ty, idx_l)
-                    params = bcast(gmean(params, ofs), params, ofs)
+                    with jax.named_scope("local_sgd"):
+                        params, train_loss = maml_all(params, tx, ty, idx_l)
+                    with jax.named_scope("mix"):
+                        params = bcast(gmean(params, ofs), params, ofs)
                     eff_nbr = nbr_count
 
                 elif method == "fedamp":
                     # one gather; attention rows for the local slab only
                     allp = aggregation.gather_clients(params)
-                    xi = baselines.fedamp_weights(
-                        allp, sim.fedamp_sigma, pm, sim.fedamp_self_weight)
-                    xi_l = slab(xi, ofs)                        # (S, N)
-                    cloud_l = jax.tree.map(
-                        lambda p: jnp.einsum(
-                            "sm,m...->s...", xi_l.astype(jnp.float32),
-                            p.astype(jnp.float32)).astype(p.dtype), allp)
-                    params, train_loss = amp_all(params, cloud_l, tx, ty,
-                                                 idx_l)
+                    with jax.named_scope("mix"):
+                        xi = baselines.fedamp_weights(
+                            allp, sim.fedamp_sigma, pm,
+                            sim.fedamp_self_weight)
+                        xi_l = slab(xi, ofs)                    # (S, N)
+                        cloud_l = jax.tree.map(
+                            lambda p: jnp.einsum(
+                                "sm,m...->s...", xi_l.astype(jnp.float32),
+                                p.astype(jnp.float32)).astype(p.dtype),
+                            allp)
+                    with jax.named_scope("local_sgd"):
+                        params, train_loss = amp_all(params, cloud_l, tx, ty,
+                                                     idx_l)
                     eff_nbr = nbr_count
 
                 elif method == "pfedwn":
                     # 1. everyone trains locally on their shard
-                    params, train_loss = local_all(params, tx, ty, idx_l)
+                    with jax.named_scope("local_sgd"):
+                        params, train_loss = local_all(params, tx, ty, idx_l)
                     # 2-4. ONE per-round gather of the peer stack; EM and
                     # the erasure-gated Eq-1 mix run replicated
                     allp = aggregation.gather_clients(params)
-                    target = jax.tree.map(lambda p: p[0], allp)
-                    neighbors = jax.tree.map(lambda p: p[nbr], allp)
-                    if sim.em_uniform:
-                        pi_new = jnp.full((M,), 1.0 / max(M, 1))
-                    else:
-                        _, pi_new, _ = em_refine_loop(
-                            fns, neighbors, pi, x0, y0, iters=sim.em_iters,
-                            lr=lr, min_weight=em_min_w,
-                            component_steps=sim.em_component_steps)
-                    if sim.erasures:
-                        link_ok = link_success_mask(k_erase, p_err_nbr)
-                    else:
-                        link_ok = jnp.ones((M,), bool)
-                    mixed = aggregation.mix_params_with_erasures(
-                        target, neighbors, pi_new, sim.alpha, link_ok)
+                    with jax.named_scope("em"):
+                        target = jax.tree.map(lambda p: p[0], allp)
+                        neighbors = jax.tree.map(lambda p: p[nbr], allp)
+                        if sim.em_uniform:
+                            pi_new = jnp.full((M,), 1.0 / max(M, 1))
+                        else:
+                            _, pi_new, _ = em_refine_loop(
+                                fns, neighbors, pi, x0, y0,
+                                iters=sim.em_iters, lr=lr,
+                                min_weight=em_min_w,
+                                component_steps=sim.em_component_steps)
+                    with jax.named_scope("mix"):
+                        if sim.erasures:
+                            link_ok = link_success_mask(k_erase, p_err_nbr)
+                        else:
+                            link_ok = jnp.ones((M,), bool)
+                        mixed = aggregation.mix_params_with_erasures(
+                            target, neighbors, pi_new, sim.alpha, link_ok)
                     # 5. target post-aggregation pass, written back only on
                     # the shard holding global client 0
-                    mixed, loss0 = sgd_one(mixed, tx0, ty0, idx[0])
-                    is0 = (jnp.arange(S) + ofs) == 0
-                    params = jax.tree.map(
-                        lambda s, t: jnp.where(
-                            is0.reshape((-1,) + (1,) * (s.ndim - 1)),
-                            t[None].astype(s.dtype), s),
-                        params, mixed)
+                    with jax.named_scope("post_sgd"):
+                        mixed, loss0 = sgd_one(mixed, tx0, ty0, idx[0])
+                        is0 = (jnp.arange(S) + ofs) == 0
+                        params = jax.tree.map(
+                            lambda s, t: jnp.where(
+                                is0.reshape((-1,) + (1,) * (s.ndim - 1)),
+                                t[None].astype(s.dtype), s),
+                            params, mixed)
                     pi = pi_new
                     train_loss = jnp.where(is0, loss0, train_loss)
                     link_rate = link_success_rate(link_ok)
@@ -791,6 +872,7 @@ class FederatedSimulation:
         pmf = self.participants.astype(jnp.float32)
         denom = jnp.maximum(jnp.sum(pmf), 1.0)
 
+        @jax.named_scope("eval")
         def eval_fn(params, data: BlockData, target: TargetData):
             ofs = jax.lax.axis_index("clients") * S
             is0 = (jnp.arange(S) + ofs) == 0
@@ -812,7 +894,7 @@ class FederatedSimulation:
 
         return eval_fn
 
-    def sharded_block_fn(self, method: str):
+    def sharded_block_fn(self, method: str) -> RoundBlock:
         """Sharded analogue of :meth:`block_fn`: the same scan-over-rounds
         block wrapped in ``compat.shard_map`` over the client mesh —
         donated state, one executable per (method, block length), taps
@@ -862,8 +944,8 @@ class FederatedSimulation:
                     axis_names={"clients"}, check_vma=False)
                 return mapped(*state, *data)
 
-            self._sharded_blocks[method] = jax.jit(
-                block, static_argnums=(2,), donate_argnums=(0,))
+            self._sharded_blocks[method] = RoundBlock(
+                block, f"{method}_sharded_block")
         return self._sharded_blocks[method]
 
     def _run_scan(self, method: str) -> Dict[str, Any]:
@@ -872,8 +954,9 @@ class FederatedSimulation:
         state's placement and the cache key differ."""
         sim, rec = self.sim, self.recorder
         data = self.staged_data()
-        state = (self.initial_sharded_state() if self.engine == "sharded"
-                 else self.initial_state())
+        with rec.span("init_state", engine=self.engine):
+            state = (self.initial_sharded_state()
+                     if self.engine == "sharded" else self.initial_state())
         blocks = block_schedule(sim.rounds, sim.eval_every)
         history: Dict[str, Any] = {"target_acc": [], "pi": [],
                                    "mean_participant_acc": []}
@@ -899,10 +982,10 @@ class FederatedSimulation:
                             em_entropy=float(ent[i]),
                             link_success_rate=float(lsr[i]),
                             effective_neighbors=float(eff[i]))
+                pi_host = np.asarray(pi) if method == "pfedwn" else None
             rnd += length
             history["target_acc"].append(t_acc)
             history["mean_participant_acc"].append(mean_acc)
-            pi_host = np.asarray(pi) if method == "pfedwn" else None
             if method == "pfedwn":
                 history["pi"].append(pi_host)
             rec.record_eval(rnd - 1, target_acc=t_acc,

@@ -36,6 +36,8 @@ import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from repro import compat
+from repro.utils.hlo_text import (OP_NAME_RE, Computation,
+                                  parse_computations)
 
 # markers checked on the lowered StableHLO text (same set the engine tests
 # have always used)
@@ -43,17 +45,9 @@ HOST_MARKERS = ("callback", "infeed", "outfeed", "CopyToHost")
 
 _HOST_CUSTOM_CALL = 'custom_call_target="xla_python_cpu_callback'
 
-_COMP_HEADER_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
-# comma-separated callee lists only ever appear inside braces
-# (branch_computations={%a, %b}); a bare ref is a single name
-_CALLEE_RE = re.compile(
-    r"\b(condition|body|to_apply|calls|true_computation|false_computation|"
-    r"branch_computations)="
-    r"(?:\{([^}]*)\}|(%?[\w.\-]+))")
 _COLLECTIVE_RE = re.compile(
     r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(?:-start)?\(")
-_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _SOURCE_LINE_RE = re.compile(r"source_line=(\d+)")
 _F64_RE = re.compile(r"\bf64\[")
 
@@ -63,41 +57,6 @@ _KIND = {"all-reduce": "reduce", "all-gather": "gather",
 
 
 # ----------------------------------------------------- compiled-HLO parsing
-
-@dataclasses.dataclass
-class Computation:
-    name: str
-    is_entry: bool
-    lines: List[str]
-    # (increments_depth, callee) — depth rises only through while bodies
-    edges: List[Tuple[bool, str]]
-
-
-def parse_computations(compiled_text: str) -> Dict[str, Computation]:
-    """Split compiled HLO text into its computations with call edges."""
-    comps: Dict[str, Computation] = {}
-    cur: Optional[Computation] = None
-    for line in compiled_text.splitlines():
-        if cur is None:
-            m = _COMP_HEADER_RE.match(line)
-            if m:
-                cur = Computation(name=m.group(2),
-                                  is_entry=bool(m.group(1)),
-                                  lines=[], edges=[])
-            continue
-        if line.strip() == "}":
-            comps[cur.name] = cur
-            cur = None
-            continue
-        cur.lines.append(line)
-        for m in _CALLEE_RE.finditer(line):
-            kind = m.group(1)
-            names = m.group(2).split(",") if m.group(2) else [m.group(3)]
-            is_loop = (kind == "body" and "while(" in line)
-            for name in names:
-                cur.edges.append((is_loop, name.strip().lstrip("%")))
-    return comps
-
 
 def computation_while_depths(comps: Dict[str, Computation]) -> Dict[str, int]:
     """while-nesting depth per computation, from the entry: the round-scan
@@ -142,7 +101,7 @@ def collective_sites(compiled_text: str) -> List[CollectiveSite]:
             if not m:
                 continue
             kind = _KIND[m.group(1)]
-            op = _OP_NAME_RE.search(line)
+            op = OP_NAME_RE.search(line)
             src = _SOURCE_LINE_RE.search(line)
             if op or src:
                 key = (kind, op.group(1) if op else "",

@@ -10,6 +10,10 @@ import jax.numpy as jnp
 
 from repro.configs.paper_cnn import CNNConfig
 
+# the named scopes the model puts on its layers' ops: a trace reads each
+# layer's device time from them, forward and backward, in every phase
+SCOPES = ("conv", "pool")
+
 
 def _conv_init(key, k, c_in, c_out, dtype=jnp.float32):
     fan_in = k * k * c_in
@@ -51,12 +55,14 @@ def _conv2d_same(x: jax.Array, w: jax.Array) -> jax.Array:
     k = w.shape[0]
     pad = k // 2
     b, h, wd, c = x.shape
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    views = [xp[:, di:di + h, dj:dj + wd, :]
-             for di in range(k) for dj in range(k)]
-    patches = jnp.concatenate(views, axis=-1)        # (B, H, W, k*k*C)
-    out = patches.reshape(b * h * wd, k * k * c) @ w.reshape(k * k * c, -1)
-    return out.reshape(b, h, wd, w.shape[-1])
+    with jax.named_scope("conv"):
+        xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+        views = [xp[:, di:di + h, dj:dj + wd, :]
+                 for di in range(k) for dj in range(k)]
+        patches = jnp.concatenate(views, axis=-1)    # (B, H, W, k*k*C)
+        out = (patches.reshape(b * h * wd, k * k * c)
+               @ w.reshape(k * k * c, -1))
+        return out.reshape(b, h, wd, w.shape[-1])
 
 
 def apply(params: Dict, x: jax.Array) -> jax.Array:
@@ -65,8 +71,9 @@ def apply(params: Dict, x: jax.Array) -> jax.Array:
     for blk in params["blocks"]:
         h = _conv2d_same(h, blk["conv"])
         h = jax.nn.relu(h + blk["bias"][None, None, None, :])
-        h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max,
-                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+        with jax.named_scope("pool"):
+            h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max,
+                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
     h = h.reshape(h.shape[0], -1)
     h = jax.nn.relu(h @ params["fc1"]["w"] + params["fc1"]["b"])
     return h @ params["fc2"]["w"] + params["fc2"]["b"]

@@ -176,8 +176,17 @@ class RunRecorder:
     def record_compile(self, name: str, compiled: Any = None,
                        cost: Optional[Dict[str, float]] = None,
                        seconds: float = 0.0) -> Dict[str, float]:
-        info = self.tracer.add_compile_event(name, compiled=compiled,
-                                             cost=cost, seconds=seconds)
+        """Record an XLA compile with its FLOP/byte estimates. ``cost`` may
+        be passed directly, or pulled from a ``Compiled`` object via
+        ``repro.compat.cost_analysis``. Returns the normalized
+        ``{"flops", "bytes_accessed"}`` dict."""
+        if cost is None and compiled is not None:
+            from repro import compat
+            cost = compat.cost_analysis(compiled)
+        cost = cost or {}
+        info = {"flops": float(cost.get("flops", 0.0)),
+                "bytes_accessed": float(cost.get(
+                    "bytes accessed", cost.get("bytes_accessed", 0.0)))}
         self.metrics.counter("compile_events").inc()
         self._emit({"type": "compile", "run_id": self.run_id, "name": name,
                     "flops": info["flops"],

@@ -1,24 +1,24 @@
 """Span tracing: host-side wall-clock phases as Chrome-trace-format JSON.
 
 A :class:`Tracer` collects complete ("ph": "X") events for the phases the
-simulator goes through — data staging, XLA compiles, round-block execution,
-eval/drain — plus compile events annotated with the FLOP/byte estimates
-that :func:`repro.compat.cost_analysis` extracts from the compiled
-executable. ``Tracer.export`` writes a file loadable by ``chrome://tracing``
-or https://ui.perfetto.dev.
-
-Module-level ``span``/``traced`` operate on an ambient tracer (swap it with
-``use_tracer``); the simulator's :class:`repro.obs.record.RunRecorder` owns
-its own tracer instance so concurrent simulations don't interleave.
+simulator goes through — data staging, XLA compiles (their spans carry the
+FLOP/byte estimates of the compiled executable), initial state, round-block
+execution, drain. ``Tracer.export`` writes a file loadable by
+``chrome://tracing`` or https://ui.perfetto.dev. Each span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+shows it on the thread that ran it, on the profiler's own clock. The
+simulator's :class:`repro.obs.record.RunRecorder` owns its own tracer
+instance so concurrent simulations don't interleave.
 """
 from __future__ import annotations
 
 import contextlib
-import functools
 import json
 import os
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+import jax
 
 
 class Span:
@@ -51,60 +51,19 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "phase", **args: Any):
-        """Context manager recording a complete event around the block."""
+        """Context manager recording a complete event around the block,
+        and a profiler annotation of the same name."""
         t0 = self._now_us()
         sp = Span(name, dict(args))
         try:
-            yield sp
+            with jax.profiler.TraceAnnotation(name):
+                yield sp
         finally:
             dur = self._now_us() - t0
             sp.duration_s = dur / 1e6
             self.events.append({"name": name, "cat": cat, "ph": "X",
                                 "ts": t0, "dur": dur, "pid": 0, "tid": 0,
                                 "args": sp.args})
-
-    def traced(self, name: Optional[str] = None, cat: str = "phase"):
-        """Decorator form of :meth:`span`."""
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*a, **kw):
-                with self.span(label, cat=cat):
-                    return fn(*a, **kw)
-
-            return wrapper
-
-        return deco
-
-    def instant(self, name: str, cat: str = "mark", **args: Any) -> None:
-        self.events.append({"name": name, "cat": cat, "ph": "i",
-                            "ts": self._now_us(), "s": "t", "pid": 0,
-                            "tid": 0, "args": dict(args)})
-
-    def add_compile_event(self, name: str, compiled: Any = None,
-                          cost: Optional[Dict[str, float]] = None,
-                          seconds: float = 0.0) -> Dict[str, float]:
-        """Record an XLA compile as a trace event annotated with FLOP/byte
-        estimates. ``cost`` may be passed directly, or pulled from a
-        ``Compiled`` object via ``repro.compat.cost_analysis``. Returns the
-        normalized ``{"flops", "bytes_accessed"}`` dict."""
-        if cost is None and compiled is not None:
-            from repro import compat
-            cost = compat.cost_analysis(compiled)
-        cost = cost or {}
-        info = {
-            "flops": float(cost.get("flops", 0.0)),
-            "bytes_accessed": float(cost.get("bytes accessed",
-                                             cost.get("bytes_accessed",
-                                                      0.0))),
-        }
-        ts = self._now_us()
-        self.events.append({"name": f"compile:{name}", "cat": "compile",
-                            "ph": "X", "ts": ts - seconds * 1e6,
-                            "dur": seconds * 1e6, "pid": 0, "tid": 0,
-                            "args": dict(info)})
-        return info
 
     # ------------------------------------------------------------- export
 
@@ -118,49 +77,3 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f, sort_keys=True)
             f.write("\n")
-
-
-# ------------------------------------------------------- ambient tracer
-
-_AMBIENT = Tracer()
-
-
-def get_tracer() -> Tracer:
-    return _AMBIENT
-
-
-def set_tracer(tracer: Tracer) -> Tracer:
-    """Install ``tracer`` as the ambient tracer; returns the previous one."""
-    global _AMBIENT
-    prev, _AMBIENT = _AMBIENT, tracer
-    return prev
-
-
-@contextlib.contextmanager
-def use_tracer(tracer: Tracer):
-    prev = set_tracer(tracer)
-    try:
-        yield tracer
-    finally:
-        set_tracer(prev)
-
-
-def span(name: str, cat: str = "phase", **args: Any):
-    """``obs.span(...)``: a span on the ambient tracer."""
-    return get_tracer().span(name, cat=cat, **args)
-
-
-def traced(name: Optional[str] = None, cat: str = "phase"):
-    """``@obs.traced(...)``: decorator spanning each call on the ambient
-    tracer (resolved at call time, so ``use_tracer`` blocks are honored)."""
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with get_tracer().span(label, cat=cat):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco

@@ -9,10 +9,11 @@ import pytest
 from repro import obs
 from repro.lint import hlo as lint_hlo
 from repro.configs.paper_cnn import CNNConfig
-from repro.core.fedsim import FederatedSimulation, FedSimConfig
+from repro.core.fedsim import PHASES, FederatedSimulation, FedSimConfig
 from repro.obs import report as obs_report
 from repro.obs.metrics import Histogram, MetricsRegistry
 from repro.obs.trace import Tracer
+from repro.models import cnn
 from repro.data import (dirichlet_partition, make_client_datasets,
                         synthetic_image_dataset, train_test_split)
 
@@ -135,41 +136,290 @@ def test_chrome_trace_schema(tmp_path):
         sp.set(rounds=3)
         with tracer.span("inner", cat="compile"):
             pass
-    tracer.instant("mark")
-    info = tracer.add_compile_event(
-        "blk", cost={"flops": 5.0, "bytes accessed": 7.0}, seconds=0.25)
-    assert info == {"flops": 5.0, "bytes_accessed": 7.0}
     path = tmp_path / "t.trace.json"
     tracer.export(str(path))
     doc = json.loads(path.read_text())
     assert isinstance(doc["traceEvents"], list) and doc["traceEvents"]
     for ev in doc["traceEvents"]:
         assert isinstance(ev["name"], str)
-        assert ev["ph"] in ("X", "i")
+        assert ev["ph"] == "X"
         assert isinstance(ev["ts"], (int, float))
         assert "pid" in ev and "tid" in ev
-        if ev["ph"] == "X":
-            assert ev["dur"] >= 0
+        assert ev["dur"] >= 0
     names = [e["name"] for e in doc["traceEvents"]]
-    assert "outer" in names and "compile:blk" in names
+    assert names == ["inner", "outer"]
     outer = next(e for e in doc["traceEvents"] if e["name"] == "outer")
     assert outer["args"]["rounds"] == 3
 
 
-def test_ambient_span_and_decorator():
-    tracer = Tracer()
-    with obs.use_tracer(tracer):
-        with obs.span("phase-a"):
-            pass
+def test_compile_span_carries_the_cost_estimate(recorded_pair):
+    """The compile span holds the executable's FLOP/byte estimate, beside
+    the RunRecord compile event; no second, backdated trace event."""
+    fused, _ = recorded_pair
+    spans = [e for e in fused.recorder.tracer.events
+             if e["name"] == "compile"]
+    records = [e for e in fused.recorder.events if e["type"] == "compile"]
+    assert len(spans) == len(records) == 2          # block lengths 1 and 2
+    for sp, rec in zip(spans, records):
+        assert sp["args"]["flops"] == rec["flops"] > 0
+        assert sp["args"]["bytes_accessed"] == rec["bytes_accessed"] > 0
+    assert all(":" not in e["name"] for e in fused.recorder.tracer.events)
 
-        @obs.traced("phase-b")
-        def work():
-            return 42
 
-        assert work() == 42
-    names = [e["name"] for e in tracer.events]
-    assert names == ["phase-a", "phase-b"]
-    assert obs.get_tracer() is not tracer          # ambient restored
+# ------------------------------------------------------- device scopes
+
+@pytest.mark.parametrize("method,engine,phases", [
+    ("pfedwn", "fused", ("local_sgd", "em", "mix", "post_sgd", "eval")),
+    ("fedavg", "fused", ("local_sgd", "mix", "eval")),
+    ("pfedwn", "sharded", ("local_sgd", "em", "mix", "post_sgd", "eval")),
+])
+def test_compiled_blocks_carry_their_scopes(method, engine, phases):
+    """Every block a run compiled is named for its method, engine and
+    length, and its ops carry each phase the method runs and both model
+    layers."""
+    model_cfg, train, test, pm, p_err = _tiny_setup()
+    sharded = engine == "sharded"
+    sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
+                              _cfg(fused=True, sharded=sharded,
+                                   shard_devices=1 if sharded else None))
+    sim.run(method)
+    scopes = sim.device_scopes()
+    infix = "sharded_" if sharded else ""
+    assert set(scopes) == {f"jit_{method}_{infix}block_r{n}" for n in (1, 2)}
+    assert set(phases) <= set(PHASES)
+    for ops in scopes.values():
+        found = set(ops.values())
+        assert {p for p, _ in found if p} == set(phases)
+        assert {l for _, l in found if l} == set(cnn.SCOPES)
+        # backward ops count with their phase and layer
+        assert ("local_sgd", "conv") in found and ("local_sgd", "pool") in found
+
+
+def test_op_scopes_reads_transform_wrapped_names():
+    hlo = "\n".join([
+        "HloModule jit_pfedwn_block_r1, is_scheduled=true",
+        "ENTRY %main.3 (p: f32[4]) -> f32[4] {",
+        '  %fusion.1 = f32[4]{0} fusion(%p), kind=kLoop, metadata={op_name='
+        '"jit(pfedwn_block_r1)/while/body/local_sgd/vmap()/while/body/'
+        'transpose(jvp(conv))/dot_general" stack_frame_id=3}',
+        '  %dot.2 = f32[4]{0} dot(%p, %p), metadata={op_name='
+        '"jit(pfedwn_block_r1)/eval/vmap(pool)/reduce_window_max"}',
+        '  %copy.3 = f32[4]{0} copy(%p), metadata={op_name='
+        '"jit(pfedwn_block_r1)/while/body/add"}',
+        "  ROOT %tuple.4 = (f32[4]{0}) tuple(%copy.3)",
+        "}"])
+    assert obs.hlo_module_name(hlo) == "jit_pfedwn_block_r1"
+    assert obs.op_scopes(hlo, PHASES, cnn.SCOPES) == {
+        "fusion.1": ("local_sgd", "conv"),
+        "dot.2": ("eval", "pool"),
+        "copy.3": (None, None),
+        "tuple.4": (None, None)}
+
+
+def test_op_scopes_fills_what_the_compiler_dropped():
+    """An op the compiler built without metadata takes its scopes from the
+    ops fused into it, then from the loop op whose body holds it."""
+    hlo = "\n".join([
+        "HloModule jit_fedavg_block_r1, is_scheduled=true",
+        "%fused_views (p0: bf16[8,16], p1: bf16[8,4]) -> bf16[8,16] {",
+        "  %p0 = bf16[8,16]{1,0} parameter(0)",
+        "  %p1 = bf16[8,4]{1,0} parameter(1)",
+        '  %slice.7 = bf16[8,4]{1,0} slice(%p1), slice={[0:8], [0:4]}, '
+        'metadata={op_name="jit(fedavg_block_r1)/local_sgd/vmap()/while/'
+        'body/jvp(conv)/slice"}',
+        "  ROOT %dynamic-update-slice.8 = bf16[8,16]{1,0} "
+        "dynamic-update-slice(%p0, %slice.7, %c, %c)",
+        "}",
+        "%body.1 (t: (s32[], bf16[8,16])) -> (s32[], bf16[8,16]) {",
+        "  %t = (s32[], bf16[8,16]{1,0}) parameter(0)",
+        "  %dus_fusion.2 = bf16[8,16]{1,0} fusion(%a, %b), kind=kLoop, "
+        "calls=%fused_views",
+        "  %copy.3 = bf16[8,16]{1,0} copy(%dus_fusion.2)",
+        "  ROOT %tuple.4 = (s32[], bf16[8,16]{1,0}) tuple(%i, %copy.3)",
+        "}",
+        "ENTRY %main.5 (p: bf16[8,16]) -> bf16[8,16] {",
+        "  %p = bf16[8,16]{1,0} parameter(0)",
+        "  %while.6 = (s32[], bf16[8,16]{1,0}) while(%init), "
+        "condition=%cond.0, body=%body.1, metadata={op_name="
+        '"jit(fedavg_block_r1)/local_sgd/vmap()/while"}',
+        "  ROOT %copy.9 = bf16[8,16]{1,0} copy(%p)",
+        "}"])
+    got = obs.op_scopes(hlo, PHASES, cnn.SCOPES)
+    assert got["dus_fusion.2"] == ("local_sgd", "conv")   # its fused ops
+    assert got["copy.3"] == ("local_sgd", None)           # its loop op
+    assert got["while.6"] == ("local_sgd", None)
+    assert got["copy.9"] == (None, None)                  # no scope at all
+    assert got["slice.7"] == ("local_sgd", "conv")
+
+
+def test_op_scopes_keeps_a_named_fusion_s_own_scopes():
+    """A fusion whose own ``op_name`` names a phase and no layer (a relu
+    rooted fusion) keeps no layer, though a conv op is fused into it."""
+    hlo = "\n".join([
+        "HloModule jit_pfedwn_block_r1, is_scheduled=true",
+        "%fused_relu (p0: f32[8], p1: f32[8]) -> f32[8] {",
+        "  %p0 = f32[8]{0} parameter(0)",
+        "  %p1 = f32[8]{0} parameter(1)",
+        '  %add.2 = f32[8]{0} add(%p0, %p1), metadata={op_name='
+        '"jit(pfedwn_block_r1)/while/body/local_sgd/vmap()/while/body/'
+        'jvp(conv)/add"}',
+        '  ROOT %maximum.3 = f32[8]{0} maximum(%add.2, %p1), metadata={'
+        'op_name="jit(pfedwn_block_r1)/while/body/local_sgd/vmap()/while/'
+        'body/jvp()/max"}',
+        "}",
+        "ENTRY %main.5 (p: f32[8]) -> f32[8] {",
+        "  %p = f32[8]{0} parameter(0)",
+        "  ROOT %add_maximum_fusion = f32[8]{0} fusion(%p, %p), kind=kLoop, "
+        "calls=%fused_relu, metadata={op_name="
+        '"jit(pfedwn_block_r1)/while/body/local_sgd/vmap()/while/body/'
+        'jvp()/max"}',
+        "}"])
+    got = obs.op_scopes(hlo, PHASES, cnn.SCOPES)
+    assert got["add_maximum_fusion"] == ("local_sgd", None)
+    assert got["add.2"] == ("local_sgd", "conv")
+
+
+def test_run_spans_are_profiler_events(tmp_path):
+    """A profiled ``run`` shows the recorder's spans as host events of the
+    ``.xplane.pb``, on the profiler's clock, each block's ops inside its
+    ``block_exec``."""
+    import glob
+
+    import jax
+    model_cfg, train, test, pm, p_err = _tiny_setup(n_clients=3)
+    sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
+                              _cfg(fused=True, rounds=2, eval_every=1))
+    sim.run("local")                                # compiles outside
+    jax.profiler.start_trace(str(tmp_path))
+    sim.run("local")
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {}
+    ops = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                host.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+                if dict(e.stats).get("hlo_module") == "jit_local_block_r1":
+                    ops.append((e.start_ns, e.start_ns + e.duration_ns))
+    assert len(host["init_state"]) == 1
+    assert len(host["block_exec"]) == len(host["drain"]) == 2
+    assert ops and all(any(a <= s and t <= b for a, b in host["block_exec"])
+                       for s, t in ops)
+
+
+# ------------------------------------------------- device time by scope
+
+def _profile(log_dir, fn):
+    """Run ``fn`` under the profiler; the ``.xplane.pb`` it wrote."""
+    import glob
+
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    return path
+
+
+def _busy_s(device_ops, lo=float("-inf"), hi=float("inf")):
+    """Mean over devices of the union of the ops' intervals in [lo, hi]."""
+    tot = 0.0
+    for ops in device_ops.values():
+        end = float("-inf")
+        for a, b in sorted((max(a, lo), min(b, hi)) for _, _, a, b in ops
+                           if b > lo and a < hi):
+            tot += max(b - max(a, end), 0.0)
+            end = max(end, b)
+    return tot / len(device_ops) / 1e9
+
+
+def test_innermost_op_takes_each_instant():
+    from repro.obs.device_time import innermost
+    # a loop op (0-100) whose body ops run 10-40 and 50-60, one of them
+    # holding a nested op 20-30; another op 120-130 outside every scope map
+    ev = [("loop", 0, 100), ("body_a", 10, 40), ("inner", 20, 30),
+          ("body_b", 50, 60), ("stray", 120, 130)]
+    assert innermost(ev, 0, 200) == {
+        "loop": 60, "body_a": 20, "inner": 10, "body_b": 10, "stray": 10}
+    assert innermost(ev, 25, 55) == {
+        "inner": 5, "body_a": 10, "loop": 10, "body_b": 5}
+    ops = {"d0": [("m", op, a * 1e9, b * 1e9) for op, a, b in ev[:4]]
+           + [("other", "stray", 120e9, 130e9)],
+           "d1": [("m", "loop", 0, 40e9)]}
+    scopes = {"m": {"loop": (None, None), "body_a": ("local_sgd", None),
+                    "inner": ("local_sgd", "conv"),
+                    "body_b": ("em", "pool")}}
+    got = obs.scope_time(ops, scopes, 0, 200e9)
+    assert got == {"unscoped": 50.0, "local_sgd": 15.0, "conv": 5.0,
+                   "em": 5.0, "pool": 5.0, "unmatched": 5.0}
+    assert got["local_sgd"] + got["em"] + got["unscoped"] \
+        + got["unmatched"] == _busy_s(ops) == 75.0
+
+
+def test_scope_time_of_a_recorded_cpu_trace(tmp_path):
+    """Two named scopes of a jitted function each read device time from a
+    CPU trace, and with the rest they add up to the busy time of the
+    window."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(x):
+        with jax.named_scope("local_sgd"):
+            x = jnp.tanh(x @ x)
+        with jax.named_scope("em"):
+            return jnp.sin(x @ x.T).sum()
+
+    f = jax.jit(fn)
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+
+    def window():
+        with jax.profiler.TraceAnnotation("window"):
+            for _ in range(2):
+                f(x).block_until_ready()
+
+    path = _profile(tmp_path, window)
+    (win,) = [(e.start_ns, e.start_ns + e.duration_ns)
+              for p in jax.profiler.ProfileData.from_file(path).planes
+              for line in p.lines for e in line.events if e.name == "window"]
+    text = f.lower(x).compile().as_text()
+    scopes = {obs.hlo_module_name(text): obs.op_scopes(
+        text, ("local_sgd", "em"))}
+    ops = obs.read_device_ops(path)
+    assert set(ops) == {"/host:CPU"}
+    got = obs.scope_time(ops, scopes, *win)
+    assert got["local_sgd"] > 0 and got["em"] > 0
+    assert got.get("unmatched", 0.0) == 0.0
+    assert sum(got.values()) == pytest.approx(_busy_s(ops, *win), rel=1e-9)
+
+
+@pytest.mark.parametrize("method,phases", [
+    ("pfedwn", ("local_sgd", "em", "mix", "post_sgd", "eval")),
+    ("fedavg", ("local_sgd", "mix", "eval")),
+])
+def test_profiled_run_reads_each_scope(tmp_path, method, phases):
+    """A profiled ``run`` of the tiny federation, reduced with the
+    simulation's own ``device_scopes()``: every phase the method runs and
+    both model layers took device time, and the phases with ``unscoped``
+    and ``unmatched`` (ops of no round block) add up to the busy time."""
+    model_cfg, train, test, pm, p_err = _tiny_setup()
+    sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
+                              _cfg(fused=True))
+    sim.run(method)                                 # compiles outside
+    ops = obs.read_device_ops(_profile(tmp_path, lambda: sim.run(method)))
+    got = obs.scope_time(ops, sim.device_scopes())
+    for name in phases + cnn.SCOPES:
+        assert got.get(name, 0.0) > 0, name
+    assert set(got) - set(phases) - set(cnn.SCOPES) <= {"unscoped",
+                                                        "unmatched"}
+    whole = sum(got.get(k, 0.0) for k in phases + ("unscoped", "unmatched"))
+    assert whole == pytest.approx(_busy_s(ops), rel=1e-9)
 
 
 # ----------------------------------------------- engine record integration
