@@ -65,15 +65,29 @@ def _conv2d_same(x: jax.Array, w: jax.Array) -> jax.Array:
         return out.reshape(b, h, wd, w.shape[-1])
 
 
+def _max_pool_2x2(h: jax.Array) -> jax.Array:
+    """2x2, stride-2 VALID max-pool as a max over a reshaped view.
+
+    An odd trailing row or column is dropped, as VALID windows drop it.
+    Under vmap over client weights, ``lax.reduce_window`` lowers on the TPU
+    to a windowed reduce with W across the lanes and a select-and-scatter
+    backward between layout copies; the reshape form is a plain reduction
+    whose backward is elementwise. Forward values are the same; on a tie
+    inside one window the cotangent is split equally among the tied
+    elements where select-and-scatter gives it all to the first."""
+    b, hh, ww, c = h.shape
+    with jax.named_scope("pool"):
+        h = h[:, :hh - hh % 2, :ww - ww % 2, :]
+        return jnp.max(h.reshape(b, hh // 2, 2, ww // 2, 2, c), axis=(2, 4))
+
+
 def apply(params: Dict, x: jax.Array) -> jax.Array:
     """x: (B, H, W, C) -> logits (B, n_classes)."""
     h = x
     for blk in params["blocks"]:
         h = _conv2d_same(h, blk["conv"])
         h = jax.nn.relu(h + blk["bias"][None, None, None, :])
-        with jax.named_scope("pool"):
-            h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max,
-                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+        h = _max_pool_2x2(h)
     h = h.reshape(h.shape[0], -1)
     h = jax.nn.relu(h @ params["fc1"]["w"] + params["fc1"]["b"])
     return h @ params["fc2"]["w"] + params["fc2"]["b"]

@@ -102,10 +102,10 @@ def _placeholder_sets(n, k, model_cfg):
     return [SyntheticImageDataset(x, y, model_cfg.n_classes)] * n
 
 
-def test_fused_pfedwn_block_fits_one_v5e(one_chip):
-    """The worst case of chip_smoke.py: M=31 neighbours. Arguments,
-    outputs and temporaries of the whole block, eval included, fit one
-    chip's HBM."""
+@pytest.fixture(scope="module")
+def pfedwn_block(one_chip):
+    """The worst case of chip_smoke.py, M=31 neighbours: the fused pfedwn
+    block compiled for one v5e, with the staged data's shapes."""
     mc = cifar10_cnn()
     n, k, kt = SMOKE_N, SMOKE_K_TRAIN, SMOKE_K_TEST
     sim = FederatedSimulation(
@@ -124,7 +124,13 @@ def test_fused_pfedwn_block_fits_one_v5e(one_chip):
         s((cfg.adapt_subset,), jnp.int32))
     state = jax.tree.map(lambda a: s(a.shape, a.dtype), sim.initial_state())
     assert state[1].shape == (31,)
-    compiled = sim.block_fn("pfedwn").lower(state, data, 2).compile()
+    return sim.block_fn("pfedwn").lower(state, data, 2).compile(), data
+
+
+def test_fused_pfedwn_block_fits_one_v5e(pfedwn_block):
+    """Arguments, outputs and temporaries of the whole block, eval
+    included, fit one chip's HBM."""
+    compiled, data = pfedwn_block
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
@@ -135,3 +141,15 @@ def test_fused_pfedwn_block_fits_one_v5e(one_chip):
         f"pfedwn block needs {total / 2**30:.2f} GiB "
         f"(args {mem.argument_size_in_bytes / 2**30:.2f}, temp "
         f"{mem.temp_size_in_bytes / 2**30:.2f})")
+
+
+def test_fused_pfedwn_block_pools_without_windows(pfedwn_block):
+    """The CNN's max-pool compiles to plain reductions, in every phase,
+    forward and backward: no select-and-scatter anywhere in the block and
+    no reduce-window from the `pool` scope. Under vmap over client weights
+    the windowed form ran far below the chip's HBM roofline."""
+    text = pfedwn_block[0].as_text()
+    assert "select-and-scatter" not in text
+    pool_windows = [line.strip() for line in text.splitlines()
+                    if " reduce-window(" in line and "pool" in line]
+    assert not pool_windows, pool_windows[:3]
