@@ -1,5 +1,8 @@
-"""The paper's own model scale: 3-layer CNN (MNIST) / compact ResNet-ish
-CNN (CIFAR), per Sec V-A. Used by the paper-faithful federated simulation."""
+"""The simulator's client CNNs, per Sec V-A: for MNIST (28x28x1) three conv
+blocks of 16/32/32 channels and a hidden layer of 64; for CIFAR (32x32x3)
+three conv blocks of 32/64/64 channels and a hidden layer of 128, where the
+paper trains ResNet-18. Each block is a 3x3 conv, bias, ReLU and a 2x2
+max-pool; no residual connections."""
 from __future__ import annotations
 
 from dataclasses import dataclass

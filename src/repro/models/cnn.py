@@ -1,6 +1,7 @@
-"""The paper's client model scale: a compact CNN classifier (Sec V-A uses a
-3-layer CNN for MNIST and ResNet18 for CIFAR; we use a 3-block CNN with
-residual connections — the same ballpark, pure JAX)."""
+"""The client model: a CNN classifier of conv blocks (3x3 conv, bias, ReLU,
+2x2 max-pool; no residual connections), a hidden dense layer and a linear
+head, in pure JAX. Sec V-A uses a 3-layer CNN for MNIST and ResNet-18 for
+CIFAR; the presets are in ``configs/paper_cnn``."""
 from __future__ import annotations
 
 from typing import Dict

@@ -7,6 +7,8 @@ one of the 31 neighbours selected. What the chip's compiler would refuse
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file."""
+import re
+
 import numpy as np
 import pytest
 
@@ -14,13 +16,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.configs.paper_cnn import cifar10_cnn
-from repro.core.fedsim import BlockData, FederatedSimulation, FedSimConfig
+from repro.core.fedsim import (PHASES, BlockData, FederatedSimulation,
+                               FedSimConfig)
 from repro.data.synthetic import SyntheticImageDataset
 from repro.kernels.em_posterior import em_posterior
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.weighted_agg import weighted_agg
 from repro.models import cnn
+from repro.utils.hlo_text import CALLEE_RE, callee_names, parse_computations
 
 V5E_HBM_BYTES = 16 * 2 ** 30
 
@@ -153,3 +158,66 @@ def test_fused_pfedwn_block_pools_without_windows(pfedwn_block):
     pool_windows = [line.strip() for line in text.splitlines()
                     if " reduce-window(" in line and "pool" in line]
     assert not pool_windows, pool_windows[:3]
+
+
+def _block_cycles(text):
+    """``{op: the compiler's estimated cycles x its loops' trip counts}``
+    for every op of the block with an estimate: the entry runs once, a
+    loop's body as often as its condition's bound (each loop is a scan
+    from 0), a branch as often as its caller. Fused computations and
+    reducers are inside their callers' estimates."""
+    comps = parse_computations(text)
+    runs = {c.name: 1 for c in comps.values() if c.is_entry}
+    todo = list(runs)
+    while todo:
+        name = todo.pop()
+        for line in comps[name].lines:
+            loop = re.search(r" while\(.*condition=%?([\w.\-]+), "
+                             r"body=%?([\w.\-]+)", line)
+            if loop:
+                bound = re.search(r"constant\((\d+)\)",
+                                  "\n".join(comps[loop.group(1)].lines))
+                trips = int(bound.group(1))
+                runs[loop.group(1)] = runs[name] * (trips + 1)
+                runs[loop.group(2)] = runs[name] * trips
+                todo.extend(loop.groups())
+            for m in CALLEE_RE.finditer(line):
+                if m.group(1) in ("true_computation", "false_computation",
+                                  "branch_computations"):
+                    for callee in callee_names(m):
+                        runs[callee] = runs[name]
+                        todo.append(callee)
+    out = {}
+    for name, n in runs.items():
+        for line in comps[name].lines:
+            op = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s", line)
+            est = re.search(r'"estimated_cycles":"(\d+)"', line)
+            if op and est:
+                out[op.group(1)] = int(est.group(1)) * n
+    return out
+
+
+def test_fused_pfedwn_block_puts_its_work_in_phases(pfedwn_block):
+    """The scopes that ``FederatedSimulation.device_scopes`` reads
+    (``obs.op_scopes``) leave under 1% of the block's estimated device
+    cycles without a phase: the block's staging copies, the round key's
+    split, the taps and what the compiler hoists out of a phase's loop."""
+    text = pfedwn_block[0].as_text()
+    scopes = obs.op_scopes(text, PHASES, cnn.SCOPES)
+    cycles = _block_cycles(text)
+    unscoped = {op: c for op, c in cycles.items() if scopes[op][0] is None}
+    share = sum(unscoped.values()) / sum(cycles.values())
+    assert share < 0.01, (share, sorted(unscoped.items(),
+                                        key=lambda kv: -kv[1])[:5])
+
+
+def test_fused_pfedwn_block_gathers_images_as_flat_rows(pfedwn_block):
+    """Every minibatch gather from the (K, H, W, C) image stacks slices
+    flat rows of H*W*C values (`fedsim.batch_rows`). At C = 3 the compiler
+    lays the stack out with C outside H and W; on a v5e, local SGD that
+    gathered (H, W, C) slices from that layout departed from the reference
+    after two rounds, and with flat rows it did not."""
+    text = pfedwn_block[0].as_text()
+    sizes = re.findall(r" gather\(.*?slice_sizes=\{([\d,]+)\}", text)
+    assert not [s for s in sizes if s.endswith(",32,32,3")], sizes
+    assert [s for s in sizes if s.endswith(",3072")], sizes
