@@ -44,26 +44,22 @@ def init_params(key, cfg: CNNConfig, dtype=jnp.float32) -> Dict:
 
 
 def _conv2d_same(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Stride-1 SAME conv (odd kernel) as shifted views + one einsum.
+    """Stride-1 SAME conv (odd kernel) as one ``lax.conv_general_dilated``,
+    at the configuration's default matmul precision.
 
-    The simulator vmaps the model over per-client *weights*; under vmap,
-    ``lax.conv_general_dilated`` lowers to a grouped convolution that
-    XLA:CPU executes orders of magnitude slower than the equivalent
-    contraction. Gathering the k·k shifted views and contracting them with
-    a single einsum keeps the vmapped path on batched-GEMM kernels —
-    numerically the same sum, so training trajectories are unaffected up to
-    float addition order."""
-    k = w.shape[0]
-    pad = k // 2
-    b, h, wd, c = x.shape
+    The simulator vmaps the model over per-client weights, and ``vmap``
+    lowers this call: with x and w both batched (local SGD, eval) to one
+    grouped conv with a group per client, clients x channels on the lanes;
+    with only w batched (the EM's first layer, over the target's data) to
+    one dense conv with every neighbour's filters as outputs. A patch
+    tensor of nine shifted views, (B, H, W, 9*C) with 9 to 288 values on
+    the lanes, contracted by one matmul, took 3.1 to 5.3 times the round
+    time of this form on a TPU v5e at the MNIST and CIFAR-10 presets, and
+    on XLA:CPU a vmapped MNIST grad step (8 clients x 16 images) reads
+    22 ms in this form against 31 ms in that one."""
     with jax.named_scope("conv"):
-        xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-        views = [xp[:, di:di + h, dj:dj + wd, :]
-                 for di in range(k) for dj in range(k)]
-        patches = jnp.concatenate(views, axis=-1)    # (B, H, W, k*k*C)
-        out = (patches.reshape(b * h * wd, k * k * c)
-               @ w.reshape(k * k * c, -1))
-        return out.reshape(b, h, wd, w.shape[-1])
+        return jax.lax.conv_general_dilated(
+            x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
 def _max_pool_2x2(h: jax.Array) -> jax.Array:

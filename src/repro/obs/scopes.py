@@ -9,10 +9,10 @@ its module and its HLO name; :func:`op_scopes` maps that name to the phase
 and the layer the op ran in.
 
 The compiler drops the metadata of some ops it builds: a fusion it makes
-from a concatenate (the conv's shifted views, written into one buffer) or
-a copy it adds for a layout. Only an op with no ``op_name`` takes its
-scopes from elsewhere: from the ops fused into it, then from the loop or
-branch op whose body holds it. An op whose own ``op_name`` names no layer
+from a concatenate (its inputs written into one buffer) or a copy it adds
+for a layout. Only an op with no ``op_name`` takes its scopes from
+elsewhere: from the ops fused into it, then from the loop or branch op
+whose body holds it. An op whose own ``op_name`` names no layer
 (a fusion rooted at a bias add) keeps none, whatever is fused into it.
 """
 from __future__ import annotations

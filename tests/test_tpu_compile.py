@@ -221,3 +221,21 @@ def test_fused_pfedwn_block_gathers_images_as_flat_rows(pfedwn_block):
     sizes = re.findall(r" gather\(.*?slice_sizes=\{([\d,]+)\}", text)
     assert not [s for s in sizes if s.endswith(",32,32,3")], sizes
     assert [s for s in sizes if s.endswith(",3072")], sizes
+
+
+def test_fused_pfedwn_block_convolves_without_patches(pfedwn_block):
+    """The CNN's convs compile to convolutions, in every phase, forward and
+    backward: no concatenate from the `conv` scope. Nine shifted views
+    concatenated into a (B, H, W, 9*C) patch tensor and one matmul read
+    3.3 times the bytes of a conv per SGD step, and took half of every
+    round on a v5e."""
+    text = pfedwn_block[0].as_text()
+    scopes = obs.op_scopes(text, PHASES, cnn.SCOPES)
+    ops = {}
+    for line in text.splitlines():
+        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?\s"
+                     r"(concatenate|convolution)\(", line)
+        if m and scopes[m.group(1)][1] == "conv":
+            ops.setdefault(m.group(2), []).append(m.group(1))
+    assert not ops.get("concatenate"), ops.get("concatenate")[:3]
+    assert ops.get("convolution"), sorted(ops)
