@@ -1,19 +1,8 @@
-"""Federated-round-loop perf trajectory: fused scan-over-rounds engine vs
-the legacy host-driven loop, for all six methods at N=8 and N=32 clients,
-plus the client-sharded engine swept over the client-mesh sizes that divide
-the visible device count.
-
-Emits ``name,us_per_call,derived`` CSV lines (harness convention) and writes
-``BENCH_fedsim.json`` at the repo root with before/after rounds-per-second —
-the "before" numbers are the legacy engine, the "after" numbers the fused
-engine, so later PRs can extend the trajectory instead of re-measuring the
-baseline. Every writer goes through ``_merge_write``, which read-updates the
-existing report and preserves top-level sections it doesn't own
-(``obs_overhead``, ``sharded``, ``pfedwn_hoist``, anything future).
-
-``smoke``/``sharded_smoke``/``obs_smoke`` are the CI entries: seconds-scale
-shapes that run the engines and assert they still agree, so the bench
-harness can't silently rot.
+"""CI smokes of the round engine on a tiny federation (XLA:CPU, seconds):
+``obs_smoke`` runs an instrumented simulation and checks its RunRecord,
+``sharded_smoke`` checks the client-mesh placement against one device on
+all six methods. Both print ``name,us_per_call,derived`` CSV lines
+(harness convention); neither is a speed measurement.
 """
 from __future__ import annotations
 
@@ -21,7 +10,7 @@ import json
 import os
 import tempfile
 import time
-from typing import Dict, Optional
+from typing import Optional
 
 import numpy as np
 
@@ -31,24 +20,17 @@ from repro.core.fedsim import METHODS, FederatedSimulation, FedSimConfig
 from repro.data import (make_client_datasets, synthetic_image_dataset,
                         train_test_split)
 
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-OUT_PATH = os.path.join(REPO_ROOT, "BENCH_fedsim.json")
 
-
-def build_sim(n_clients: int, *, fused: bool, rounds: int, eval_every: int,
+def build_sim(n_clients: int, *, rounds: int, eval_every: int,
               samples: int = 0, image_size: int = 8, batch: int = 32,
-              seed: int = 0, taps: bool = True,
-              sharded: bool = False, shard_devices: Optional[int] = None,
+              seed: int = 0, sharded: bool = False,
+              shard_devices: Optional[int] = None,
               record_dir: str | None = None,
               run_name: str | None = None) -> FederatedSimulation:
-    """All-participants network with mild random link error — the learning
-    hot path is what's timed, not the channel layer.
-
-    Clients get an even random shard (~64 samples each by default) rather
-    than a Dirichlet split: the bench measures engine overhead at a fixed
-    steps-per-round, and the Dirichlet partitioner's remainder handling
-    hands one client a multiple of the mean, which would silently multiply
-    every method's per-round compute."""
+    """All-participants network with mild random link error. Clients get
+    an even random shard (~64 samples each by default) rather than a
+    Dirichlet split, so every client runs about the same number of steps
+    per round."""
     samples = samples or 64 * n_clients
     base = synthetic_image_dataset(seed, samples, image_size=image_size,
                                    n_classes=10)
@@ -66,122 +48,15 @@ def build_sim(n_clients: int, *, fused: bool, rounds: int, eval_every: int,
                           n_classes=10)
     cfg = FedSimConfig(rounds=rounds, batch_size=batch, lr=0.05, alpha=0.7,
                        em_iters=2, em_subset=32, adapt_subset=32,
-                       eval_every=eval_every, seed=seed, fused=fused,
-                       sharded=sharded, shard_devices=shard_devices,
-                       taps=taps, record_dir=record_dir, run_name=run_name)
+                       eval_every=eval_every, seed=seed, sharded=sharded,
+                       shard_devices=shard_devices, record_dir=record_dir,
+                       run_name=run_name)
     return FederatedSimulation(model_cfg, train_sets, test_sets, pm, p_err,
                                cfg)
 
 
-def time_method(sim: FederatedSimulation, method: str,
-                repeat: int = 1) -> Dict[str, float]:
-    """rounds/sec + per-round latency, compile/warmup excluded; with
-    ``repeat`` > 1, keeps the fastest run (noise floor for the obs-overhead
-    comparison)."""
-    sim.run(method)                       # warmup: compile every shape
-    dt = float("inf")
-    for _ in range(repeat):
-        t0 = time.perf_counter()
-        sim.run(method)
-        dt = min(dt, time.perf_counter() - t0)
-    rounds = sim.sim.rounds
-    return {"rounds_per_sec": rounds / dt, "round_latency_ms": dt / rounds * 1e3,
-            "total_s": dt}
-
-
-def run(rounds: int = 8, eval_every: int = 1) -> Dict:
-    import jax
-    results: Dict[str, Dict] = {}
-    for n in (8, 32):
-        # records emitted by default: runs/fedsim_{engine}_N{n}_seed0.jsonl
-        sims = {engine: build_sim(n, fused=(engine == "fused"),
-                                  rounds=rounds, eval_every=eval_every,
-                                  record_dir=os.path.join(REPO_ROOT, "runs"))
-                for engine in ("legacy", "fused")}
-        results[f"N={n}"] = {}
-        for method in METHODS:
-            row: Dict[str, float] = {}
-            for engine, sim in sims.items():
-                t = time_method(sim, method)
-                row[f"{engine}_rounds_per_sec"] = round(t["rounds_per_sec"], 3)
-                row[f"{engine}_round_latency_ms"] = round(
-                    t["round_latency_ms"], 2)
-            row["speedup"] = round(row["fused_rounds_per_sec"]
-                                   / row["legacy_rounds_per_sec"], 2)
-            results[f"N={n}"][method] = row
-            emit(f"fedsim_{method}_N{n}",
-                 row["fused_round_latency_ms"] * 1e3,
-                 f"fused_rps={row['fused_rounds_per_sec']:.2f};"
-                 f"legacy_rps={row['legacy_rounds_per_sec']:.2f};"
-                 f"speedup={row['speedup']:.2f}x")
-    report = {
-        "bench": "fedsim_round_loop",
-        "device": jax.devices()[0].platform,
-        "jax_version": jax.__version__,
-        "config": {"rounds": rounds, "eval_every": eval_every,
-                   "batch_size": 32, "image_size": 8, "em_iters": 2,
-                   "em_subset": 32, "model": "cnn(4,8)/h16",
-                   "samples_per_client": 64, "partition": "even"},
-        "note": "legacy = host-driven per-round loop (before); "
-                "fused = donated scan-over-rounds engine (after)",
-        "results": results,
-    }
-    # trajectory policy: a base-sweep re-run updates only its own keys;
-    # sections other benches appended (obs_overhead, sharded, ...) survive
-    return _merge_write(report)
-
-
-def _merge_write(updates: Dict) -> Dict:
-    """Read-update-write ``BENCH_fedsim.json``: only the top-level keys in
-    ``updates`` are replaced; unknown keys (obs_overhead, sharded, sections
-    future PRs add) pass through byte-identical. Returns the merged report."""
-    report: Dict = {}
-    if os.path.exists(OUT_PATH):
-        with open(OUT_PATH) as f:
-            report = json.load(f)
-    report.update(updates)
-    with open(OUT_PATH, "w") as f:
-        json.dump(report, f, indent=1)
-        f.write("\n")
-    return report
-
-
-def obs_overhead(rounds: int = 8) -> Dict:
-    """Extend the BENCH_fedsim.json trajectory with the telemetry-tap cost:
-    fused pfedwn rounds/sec with the device-side metrics tap on vs off
-    (same shape as the base sweep's N=8 row). Appends an ``obs_overhead``
-    entry to the existing report — the legacy/fused baseline is NOT
-    re-measured (ROADMAP perf-trajectory policy) — and asserts the tap
-    costs < 5% of fused throughput."""
-    if not os.path.exists(OUT_PATH):
-        raise RuntimeError(
-            f"{OUT_PATH} missing: run `python -m benchmarks.run --only "
-            "fedsim_bench` first (obs_overhead extends the trajectory, it "
-            "does not re-measure the baseline)")
-    rps = {}
-    for taps in (False, True):
-        sim = build_sim(8, fused=True, rounds=rounds, eval_every=1,
-                        taps=taps)
-        rps[taps] = time_method(sim, "pfedwn", repeat=3)["rounds_per_sec"]
-    overhead_pct = (rps[False] - rps[True]) / rps[False] * 100.0
-    report = _merge_write({"obs_overhead": {
-        "note": "fused pfedwn N=8, device-side metrics tap on vs off "
-                "(taps ride the round scan, drain at eval boundaries)",
-        "rounds": rounds,
-        "taps_off_rounds_per_sec": round(rps[False], 3),
-        "taps_on_rounds_per_sec": round(rps[True], 3),
-        "overhead_pct": round(overhead_pct, 2),
-    }})
-    emit("fedsim_obs_overhead", 0.0,
-         f"taps_on_rps={rps[True]:.2f};taps_off_rps={rps[False]:.2f};"
-         f"overhead={overhead_pct:.2f}%")
-    assert overhead_pct < 5.0, (
-        f"metrics-tap overhead {overhead_pct:.2f}% exceeds the 5% budget")
-    return report["obs_overhead"]
-
-
 def obs_smoke() -> None:
-    """CI stage entry (seconds): run a tiny instrumented fused simulation,
+    """CI stage entry (seconds): run a tiny instrumented simulation,
     emit obs_smoke.jsonl + Chrome trace, and validate the RunRecord schema
     in-process. ci.sh follows up with `python -m repro.obs.report` on the
     same file.
@@ -194,7 +69,7 @@ def obs_smoke() -> None:
     out_dir = os.environ.get("OBS_SMOKE_DIR") or tempfile.mkdtemp(
         prefix="fedsim_obs_smoke_")
     os.makedirs(out_dir, exist_ok=True)
-    sim = build_sim(4, fused=True, rounds=3, eval_every=2, samples=400,
+    sim = build_sim(4, rounds=3, eval_every=2, samples=400,
                     image_size=8, batch=16, record_dir=out_dir,
                     run_name="obs_smoke")
     sim.run("pfedwn")
@@ -215,29 +90,10 @@ def obs_smoke() -> None:
          f"events={len(types)};rounds={types.count('round')};ok")
 
 
-def smoke() -> None:
-    """CI-scale guard (seconds): both engines run and agree on a tiny shape.
-    Does NOT rewrite BENCH_fedsim.json."""
-    t0 = time.perf_counter()
-    sims = {engine: build_sim(4, fused=(engine == "fused"), rounds=3,
-                              eval_every=2, samples=400, image_size=8,
-                              batch=16)
-            for engine in ("legacy", "fused")}
-    hist = {engine: sim.run("pfedwn") for engine, sim in sims.items()}
-    gap = max(abs(a - b) for a, b in zip(hist["fused"]["target_acc"],
-                                         hist["legacy"]["target_acc"]))
-    if gap > 5e-3:
-        raise AssertionError(
-            f"fused/legacy disagree on smoke shape: |Δacc|={gap:.4f}")
-    assert sims["fused"].last_run_stats["device_calls"] == 2
-    emit("fedsim_smoke", (time.perf_counter() - t0) * 1e6,
-         f"parity_gap={gap:.1e};ok")
-
-
 def sharded_smoke() -> None:
-    """CI guard for the client-sharded engine (expects forced host devices
+    """CI guard for the client-mesh placement (expects forced host devices
     via XLA_FLAGS, as ci.sh sets): all six methods on a tiny shape, sharded
-    over a 4-device client mesh vs fused, identical seeds. rounds=2 with
+    over a 4-device client mesh vs one device, identical seeds. rounds=2 with
     eval_every=2 gives blocks [1, 1] — one executable per (method, engine),
     which keeps the six-method sweep in CI seconds-to-a-minute territory."""
     import jax
@@ -249,9 +105,8 @@ def sharded_smoke() -> None:
             "XLA_FLAGS=--xla_force_host_platform_device_count=8")
     common = dict(rounds=2, eval_every=2, samples=400, image_size=8,
                   batch=16)
-    fused = build_sim(4, fused=True, **common)
-    sharded = build_sim(4, fused=True, sharded=True, shard_devices=4,
-                        **common)
+    fused = build_sim(4, **common)
+    sharded = build_sim(4, sharded=True, shard_devices=4, **common)
     worst = 0.0
     for method in METHODS:
         hf, hs = fused.run(method), sharded.run(method)
@@ -264,98 +119,3 @@ def sharded_smoke() -> None:
     assert sharded.last_run_stats["engine"] == "sharded"
     emit("fedsim_sharded_smoke", (time.perf_counter() - t0) * 1e6,
          f"devices=4;methods={len(METHODS)};worst_gap={worst:.1e};ok")
-
-
-def sharded_bench() -> Dict:
-    """Extend BENCH_fedsim.json with a ``sharded`` section: the client-
-    sharded engine at N=32, in this process, over every client-mesh size
-    that divides the visible device count. A sweep needs at least two
-    devices; on the CPU the caller forces host devices
-    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``, as
-    scripts/ci.sh does for the sharded smoke). Those share one physical
-    CPU, so the numbers measure partitioning + collective overhead, not
-    parallel speedup. fedavg covers the psum-only exchange, pfedwn the
-    all_gather + redundant target path. The legacy/fused baselines are NOT
-    re-measured."""
-    import jax
-    have = len(jax.devices())
-    if have < 2:
-        raise RuntimeError(
-            f"sharded_bench sweeps client-mesh sizes and needs >=2 devices, "
-            f"have {have}: on the CPU run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=8")
-    rounds, n = 8, 32
-    results: Dict[str, Dict] = {}
-    for d in (d for d in range(1, have + 1)
-              if have % d == 0 and n % d == 0):
-        sim = build_sim(n, fused=True, sharded=True, shard_devices=d,
-                        rounds=rounds, eval_every=1)
-        row: Dict[str, float] = {}
-        for method in ("fedavg", "pfedwn"):
-            t = time_method(sim, method)
-            row[f"{method}_rounds_per_sec"] = round(t["rounds_per_sec"], 3)
-            row[f"{method}_round_latency_ms"] = round(
-                t["round_latency_ms"], 2)
-        results[f"devices={d}"] = row
-    dev = jax.devices()[0]
-    section = {
-        "note": "client-sharded scan engine, N=32, client mesh over D "
-                "devices; on forced host devices (one physical CPU) an "
-                "overhead sweep, not a scaling claim; baselines above not "
-                "re-measured",
-        "device": {"platform": dev.platform, "kind": dev.device_kind,
-                   "count": have},
-        "rounds": rounds,
-        "results": results,
-    }
-    report = _merge_write({"sharded": section})
-    for d, row in results.items():
-        emit(f"fedsim_sharded_{d.replace('=', '')}",
-             row["pfedwn_round_latency_ms"] * 1e3,
-             f"pfedwn_rps={row['pfedwn_rounds_per_sec']:.2f};"
-             f"fedavg_rps={row['fedavg_rounds_per_sec']:.2f}")
-    return report["sharded"]
-
-
-def hoist_bench(rounds: int = 8) -> Dict:
-    """Extend BENCH_fedsim.json with a ``pfedwn_hoist`` section: fused
-    pfedwn N=32 re-timed after hoisting the EM loop's per-iteration
-    component-stack touches (single-vjp E-step + refinement, dead final
-    refinement skipped). The ``results`` baseline rows are NOT re-measured;
-    the pre-hoist latency is read from the stored trajectory."""
-    if not os.path.exists(OUT_PATH):
-        raise RuntimeError(f"{OUT_PATH} missing: run fedsim_bench first")
-    with open(OUT_PATH) as f:
-        before = json.load(f)["results"]["N=32"]["pfedwn"]
-    sim = build_sim(32, fused=True, rounds=rounds, eval_every=1)
-    t = time_method(sim, "pfedwn", repeat=2)
-    section = {
-        "note": "fused pfedwn N=32 after the EM-loop hoist (one vjp touch "
-                "of the component stack per EM iteration; final dead "
-                "refinement dropped); before = the stored fused baseline, "
-                "which is kept unmeasured per the trajectory policy",
-        "rounds": rounds,
-        "before_round_latency_ms": before["fused_round_latency_ms"],
-        "after_round_latency_ms": round(t["round_latency_ms"], 2),
-        "after_rounds_per_sec": round(t["rounds_per_sec"], 3),
-        "speedup_vs_stored_baseline": round(
-            before["fused_round_latency_ms"] / t["round_latency_ms"], 2),
-    }
-    report = _merge_write({"pfedwn_hoist": section})
-    emit("fedsim_pfedwn_hoist", t["round_latency_ms"] * 1e3,
-         f"before_ms={section['before_round_latency_ms']};"
-         f"after_ms={section['after_round_latency_ms']};"
-         f"speedup={section['speedup_vs_stored_baseline']:.2f}x")
-    return report["pfedwn_hoist"]
-
-
-def main() -> None:
-    report = run()
-    n32 = report["results"]["N=32"]["pfedwn"]
-    emit("fedsim_bench", 0.0,
-         f"wrote BENCH_fedsim.json;pfedwn_N32_speedup={n32['speedup']:.2f}x")
-    obs_overhead()
-
-
-if __name__ == "__main__":
-    main()

@@ -25,12 +25,7 @@ ALL = {
     "kernels": kernels_bench.main,
     "roofline": roofline.main,
     "ablations": ablations.main,
-    "fedsim_bench": fedsim_bench.main,
-    "fedsim_smoke": fedsim_bench.smoke,
-    "fedsim_obs_overhead": fedsim_bench.obs_overhead,
-    "fedsim_sharded": fedsim_bench.sharded_bench,
     "fedsim_sharded_smoke": fedsim_bench.sharded_smoke,
-    "fedsim_hoist": fedsim_bench.hoist_bench,
     "obs_smoke": fedsim_bench.obs_smoke,
     "lint_smoke": lint_smoke.main,
 }

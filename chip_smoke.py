@@ -60,8 +60,9 @@ SHARDED_CHIPS = 4
 # method and engine compiles one block length (blocks [1, 1, 1, 1])
 # instead of two ([1, 2, 1]), halving that run's compile time
 SHARDED_EVAL_EVERY = 1
-# the sharded-vs-fused target-accuracy tolerance of
-# benchmarks.fedsim_bench.sharded_smoke
+# the sharded-vs-fused target-accuracy tolerance, the one the CPU checks
+# of the client mesh use (benchmarks.fedsim_bench.sharded_smoke,
+# tests/test_fedsim_sharded.py)
 PARITY_TOL = 5e-3
 
 

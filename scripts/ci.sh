@@ -18,23 +18,19 @@
 #
 # Stage 3 — the tier-1 suite itself (ROADMAP "Tier-1 verify").
 #
-# Stage 4 — benchmark smoke: runs the fedsim bench harness on a tiny shape
-# (seconds) so `benchmarks/fedsim_bench.py` and the fused/legacy engines
-# can't silently rot; it also asserts fused/legacy parity on that shape.
-#
-# Stage 5 — obs smoke: runs a tiny *instrumented* fused simulation that
+# Stage 4 — obs smoke: runs a tiny *instrumented* simulation that
 # emits a RunRecord JSONL + Chrome trace into a mktemp dir (OBS_SMOKE_DIR —
 # never under runs/, so CI can't clobber real run records), then invokes
 # `python -m repro.obs.report` on the emitted file; the report CLI exits
 # non-zero on any RunRecord schema violation.
 #
-# Stage 6 — sharded smoke: forces 8 host devices (XLA_FLAGS, which must be
+# Stage 5 — sharded smoke: forces 8 host devices (XLA_FLAGS, which must be
 # set before the JAX import — hence a fresh interpreter) and asserts the
-# client-sharded scan engine matches the fused engine on all six methods
-# over a real 4-device ("clients",) mesh.
+# client-mesh placement matches one device on all six methods over a real
+# 4-device ("clients",) mesh.
 #
-# Stage 7 — HLO invariants: `python -m repro.lint.hlo` lowers + compiles a
-# round block for all six methods on both the fused and sharded engines
+# Stage 6 — HLO invariants: `python -m repro.lint.hlo` lowers + compiles a
+# round block for all six methods on both placements (fused and sharded)
 # and checks the compiled artifacts (no host callbacks, donated carry,
 # rounds scanned inside, collectives ride the scan at while-depth <= 1
 # with one peer gather per round, no f64 under x64-off).
@@ -50,7 +46,7 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 # jax tries the installed libtpu and falls back to the CPU silently)
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
-echo "== stage 1/7: lint gate (source rules + fixtures + tracked-pyc) =="
+echo "== stage 1/6: lint gate (source rules + fixtures + tracked-pyc) =="
 python -m repro.lint
 if python -m repro.lint tests/fixtures/lint > /dev/null 2>&1; then
     echo "== lint gate FAILED: the violation fixtures no longer fire =="
@@ -61,7 +57,7 @@ if git ls-files | grep -E '(__pycache__|\.pyc$)' ; then
     exit 2
 fi
 
-echo "== stage 2/7: import gate (tests collect, benchmarks import, examples compile) =="
+echo "== stage 2/6: import gate (tests collect, benchmarks import, examples compile) =="
 # quiet on success (the full collected-test list is noise), but surface
 # pytest's collection errors when the gate trips
 gate_log="$(mktemp)"
@@ -77,22 +73,19 @@ python -m py_compile examples/*.py  # examples execute on import: compile only
 rm -f "$gate_log"
 trap - EXIT
 
-echo "== stage 3/7: tier-1 suite =="
+echo "== stage 3/6: tier-1 suite =="
 python -m pytest -x -q "$@"
 
-echo "== stage 4/7: benchmark smoke (fedsim_smoke) =="
-python -m benchmarks.run --only fedsim_smoke
-
-echo "== stage 5/7: obs smoke (instrumented run + RunRecord report) =="
+echo "== stage 4/6: obs smoke (instrumented run + RunRecord report) =="
 OBS_SMOKE_DIR="$(mktemp -d)"
 export OBS_SMOKE_DIR
 trap 'rm -rf "$OBS_SMOKE_DIR"' EXIT
 python -m benchmarks.run --only obs_smoke
 python -m repro.obs.report "$OBS_SMOKE_DIR/obs_smoke.jsonl"
 
-echo "== stage 6/7: sharded smoke (client mesh on forced host devices) =="
+echo "== stage 5/6: sharded smoke (client mesh on forced host devices) =="
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m benchmarks.run --only fedsim_sharded_smoke
 
-echo "== stage 7/7: HLO invariants (six methods x fused/sharded) =="
+echo "== stage 6/6: HLO invariants (six methods x fused/sharded) =="
 python -m repro.lint.hlo --engine both --devices 4

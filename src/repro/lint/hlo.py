@@ -251,20 +251,15 @@ def _check_engine(engine: str, methods: Sequence[str],
     failures: List[str] = []
     sim = _build_sim(sharded=(engine == "sharded"),
                      shard_devices=shard_devices)
-    data = sim.staged_data()
-    if engine == "sharded":
-        state = sim.initial_sharded_state()
-    else:
-        state = sim.initial_state()
+    data, state = sim.staged_data(), sim.initial_state()
     for method in methods:
         if engine == "sharded":
-            lowered = sim.sharded_block_fn(method).lower(state, data, 3)
             expectations = dict(expect_collectives=True,
                                 expect_gather=method in GATHER_METHODS)
         else:
-            lowered = sim.block_fn(method).lower(state, data, 3)
             expectations = dict(expect_collectives=False)
-        report = analyze_round_block(lowered)
+        report = analyze_round_block(
+            sim.block_fn(method).lower(state, data, 3))
         violations = check_round_block(report, **expectations)
         tag = f"{engine}/{method}"
         if violations:

@@ -16,7 +16,7 @@ Four layers (see ``docs/observability.md``):
     which sums a profiler trace's device time by them.
 
 The federated simulator owns a recorder per instance; device-side metric
-taps ride the fused engine's scan outputs and drain only at eval
+taps ride the round engine's scan outputs and drain only at eval
 boundaries, so recording never adds host syncs to the round loop.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,

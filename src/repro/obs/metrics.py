@@ -1,8 +1,8 @@
 """Metrics core: counters, gauges, histograms, and per-round timeseries.
 
 Instruments are plain host-side accumulators — nothing here touches jax.
-The federated engines feed them with values that were computed on device
-and drained at eval boundaries (fused engine) or per round (legacy engine);
+The round engine feeds them with values that were computed on device and
+drained at eval boundaries, on either client placement;
 ``MetricsRegistry.snapshot()`` renders everything as a deterministic,
 JSON-ready dict (sorted names, plain python numbers) so the same sequence
 of updates always serializes to the same bytes.

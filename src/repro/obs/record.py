@@ -231,7 +231,7 @@ _REQUIRED: Dict[str, Dict[str, Any]] = {
                 "metrics": dict},
 }
 
-_ENGINES = ("fused", "legacy")
+_ENGINES = ("fused", "sharded")
 
 
 def validate_event(event: Any) -> List[str]:

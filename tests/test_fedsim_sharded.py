@@ -121,11 +121,11 @@ def test_sharded_block_is_single_clean_executable():
             FedSimConfig(rounds=3, batch_size=16, em_iters=2, em_subset=64,
                          adapt_subset=32, eval_every=2, taps=True,
                          sharded=True, shard_devices=4))
-        state = sim.initial_sharded_state()
+        state = sim.initial_state()
         data = sim.staged_data()
         from repro.lint import hlo as lint_hlo
         for method, wants_gather in (("fedavg", False), ("pfedwn", True)):
-            lowered = sim.sharded_block_fn(method).lower(state, data, 3)
+            lowered = sim.block_fn(method).lower(state, data, 3)
             # shared analyzer: no host markers/callbacks, donated carry,
             # rounds scanned inside, psum lowered to all-reduce, the peer
             # gather present iff the method gathers, nonzero flops
@@ -158,7 +158,7 @@ def test_sharded_mesh_validation_errors():
     sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
                               _cfg(sharded=True, shard_devices=2))
     with pytest.raises(ValueError, match="divisible"):
-        sim._client_mesh_info()
+        sim.placement()
     # a mesh wider than the visible devices (D chosen to divide N so the
     # divisibility check can't mask the device-count error)
     import jax
@@ -167,4 +167,4 @@ def test_sharded_mesh_validation_errors():
     sim2 = FederatedSimulation(model_cfg, train, test, pm, p_err,
                                _cfg(sharded=True, shard_devices=d))
     with pytest.raises(ValueError, match="devices"):
-        sim2._client_mesh_info()
+        sim2.placement()

@@ -1,5 +1,5 @@
 """Telemetry subsystem: metrics-core determinism (byte-identical JSONL),
-Chrome-trace schema validity, fused/legacy RunRecord parity, and the
+Chrome-trace schema validity, fused/sharded RunRecord parity, and the
 single-executable regression for the instrumented fused block."""
 import json
 
@@ -42,15 +42,15 @@ def _cfg(**kw):
 
 @pytest.fixture(scope="module")
 def recorded_pair():
-    """(fused, legacy) tiny sims, pfedwn already run on both."""
+    """(fused, sharded on a one-device client mesh) tiny sims, pfedwn
+    already run on both."""
     model_cfg, train, test, pm, p_err = _tiny_setup()
-    fused = FederatedSimulation(model_cfg, train, test, pm, p_err,
-                                _cfg(fused=True))
-    legacy = FederatedSimulation(model_cfg, train, test, pm, p_err,
-                                 _cfg(fused=False))
+    fused = FederatedSimulation(model_cfg, train, test, pm, p_err, _cfg())
+    sharded = FederatedSimulation(model_cfg, train, test, pm, p_err,
+                                  _cfg(sharded=True, shard_devices=1))
     fused.run("pfedwn")
-    legacy.run("pfedwn")
-    return fused, legacy
+    sharded.run("pfedwn")
+    return fused, sharded
 
 
 # ---------------------------------------------------------- metrics core
@@ -180,7 +180,7 @@ def test_compiled_blocks_carry_their_scopes(method, engine, phases):
     model_cfg, train, test, pm, p_err = _tiny_setup()
     sharded = engine == "sharded"
     sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
-                              _cfg(fused=True, sharded=sharded,
+                              _cfg(sharded=sharded,
                                    shard_devices=1 if sharded else None))
     sim.run(method)
     scopes = sim.device_scopes()
@@ -288,7 +288,7 @@ def test_run_spans_are_profiler_events(tmp_path):
     import jax
     model_cfg, train, test, pm, p_err = _tiny_setup(n_clients=3)
     sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
-                              _cfg(fused=True, rounds=2, eval_every=1))
+                              _cfg(rounds=2, eval_every=1))
     sim.run("local")                                # compiles outside
     jax.profiler.start_trace(str(tmp_path))
     sim.run("local")
@@ -410,7 +410,7 @@ def test_profiled_run_reads_each_scope(tmp_path, method, phases):
     and ``unmatched`` (ops of no round block) add up to the busy time."""
     model_cfg, train, test, pm, p_err = _tiny_setup()
     sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
-                              _cfg(fused=True))
+                              _cfg())
     sim.run(method)                                 # compiles outside
     ops = obs.read_device_ops(_profile(tmp_path, lambda: sim.run(method)))
     got = obs.scope_time(ops, sim.device_scopes())
@@ -424,12 +424,13 @@ def test_profiled_run_reads_each_scope(tmp_path, method, phases):
 
 # ----------------------------------------------- engine record integration
 
-def test_fused_legacy_record_schema_parity(recorded_pair):
-    """Both engines emit the same event sequence with the same keys, and
-    the device-tap scalars agree numerically (same index stream)."""
-    fused, legacy = recorded_pair
+def test_fused_sharded_record_schema_parity(recorded_pair):
+    """Both placements emit schema-valid records with the same event
+    sequence and keys, and the device-tap scalars agree numerically (same
+    index stream)."""
+    fused, sharded = recorded_pair
     ef = fused.recorder.events
-    el = legacy.recorder.events
+    el = sharded.recorder.events
     assert [e["type"] for e in ef if e["type"] != "compile"] == \
         [e["type"] for e in el if e["type"] != "compile"]
     by_type_f = {e["type"]: e for e in ef}
@@ -460,7 +461,7 @@ def test_fused_round_events_deterministic(recorded_pair):
     fused, _ = recorded_pair
     model_cfg, train, test, pm, p_err = _tiny_setup()
     again = FederatedSimulation(model_cfg, train, test, pm, p_err,
-                                _cfg(fused=True))
+                                _cfg())
     again.run("pfedwn")
 
     def tap_lines(sim):
@@ -485,7 +486,7 @@ def test_instrumented_block_still_single_executable(recorded_pair):
 def test_taps_off_drops_round_events():
     model_cfg, train, test, pm, p_err = _tiny_setup(n_clients=3)
     sim = FederatedSimulation(model_cfg, train, test, pm, p_err,
-                              _cfg(fused=True, taps=False, rounds=2,
+                              _cfg(taps=False, rounds=2,
                                    eval_every=1))
     sim.run("local")
     types = [e["type"] for e in sim.recorder.events]
@@ -497,7 +498,7 @@ def test_run_record_files_and_report_cli(tmp_path, capsys):
     model_cfg, train, test, pm, p_err = _tiny_setup(n_clients=3)
     sim = FederatedSimulation(
         model_cfg, train, test, pm, p_err,
-        _cfg(fused=True, rounds=2, eval_every=1,
+        _cfg(rounds=2, eval_every=1,
              record_dir=str(tmp_path), run_name="rec"))
     sim.run("local")
     jsonl = tmp_path / "rec.jsonl"
